@@ -7,11 +7,11 @@ top-k merge) — on synthetic metro-area queries, and caches the winner in
 this backend pick it up.  Winners are also persisted to
 ``artifacts/autotune/geo_topk.json``.
 
-On a TPU the timings rank real kernel layouts; elsewhere the kernels run
-through the Pallas interpreter (``interpret=True``), so the sweep is
-functional end-to-end — that is the ``--smoke`` profile tier-1 runs
-(tiny shapes, two configs) to keep the autotuner exercised without a
-TPU.
+The full profile runs on a TPU only, where the timings rank real kernel
+layouts, and refuses any other backend.  The ``--smoke`` profile (tiny
+shapes, two configs) runs the kernels through the Pallas interpreter
+(``interpret=True``): tier-1 runs it to keep the autotuner exercised
+without a TPU, and its timings rank nothing.
 """
 from __future__ import annotations
 
@@ -33,13 +33,13 @@ SMOKE_CONFIGS = [(32, None), (32, 64)]
 
 
 def run(smoke: bool = False):
-    on_tpu = jax.default_backend() == "tpu"
-    interpret = not on_tpu
-    # interpreter timings only rank Python-level work, and the full sweep
-    # through it would take hours — off-TPU the full profile degrades to
-    # the smoke shapes (still functional end-to-end)
-    sweep = SMOKE_SWEEP if (smoke or not on_tpu) else FULL_SWEEP
-    smoke = smoke or not on_tpu
+    if not smoke and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "bench_autotune: the full sweep times kernels on a TPU, and the "
+            f"backend is {jax.default_backend()!r}; --smoke runs the "
+            "interpreter profile")
+    interpret = smoke
+    sweep = SMOKE_SWEEP if smoke else FULL_SWEEP
     rows = []
     for u, n, k in sweep:
         res = tune.autotune(

@@ -14,14 +14,15 @@ Two cases per profile:
 * ``mesh_d4``   — 4× the population on a 4-device mesh, same per-device
   share, with churn live.
 
-Every case runs in a *subprocess* with
+On the CPU every case runs in a *subprocess* with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``: the flag must be
 set before jax initialises, and the parent runner's jax is already up
 with one device.  Forced host devices share this machine's physical
 cores (``physical_cores`` is recorded in every row), so the honest
 weak-scaling number is the *normalized* ratio ``D x t_single / t_mesh``
-emitted by the ``derive`` hook — on real multi-chip hardware the raw
-per-tick ratio approaches it.
+emitted by the ``derive`` hook.  On an accelerator the cases run in the
+calling process on its real devices: a chip belongs to one process, and
+the caller already holds it.
 
 ``run(smoke=True)`` (or ``--smoke``) is the seconds-scale tier-1
 multi-device profile; the full sweep is the acceptance shape
@@ -161,6 +162,9 @@ def _child_case(case: dict):
 
 
 def _run_case(case: dict, timeout: float = 3600.0):
+    import jax
+    if jax.default_backend() != "cpu":
+        return [tuple(_child_case(case))]
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") +

@@ -60,6 +60,8 @@ def main() -> None:
                     help="seconds-scale profiles for modules that offer one")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     all_rows = []
     print("name,us_per_call,derived")
     mods = {m: importlib.import_module(m) for m in MODULES}

@@ -24,9 +24,9 @@ remain identical to the sharded host tick (tests/test_sharded_selection
 pins this on the Fig. 8/10 scenarios).
 
 ``FusedTickState`` keeps every pool array resident on device across
-ticks (buffers are donated on accelerators, so the state updates in
-place); per tick only small dynamic vectors cross host→device (free
-fractions, validity masks, queued node deaths, jitter draws) and only
+ticks (the state buffers are donated, so they update in place); per
+tick only small dynamic vectors cross host→device (free fractions,
+validity masks, queued node deaths, jitter draws) and only
 the per-user decisions the transport needs come back (candidates,
 active/pending, switch confirmations, traffic masks).  Shapes are
 jit-stable under churn: node/task arrays ride the engine's
@@ -77,9 +77,8 @@ COMPILE_COUNTS: collections.Counter = collections.Counter()
 
 DEATH_QUEUE_MAX = 128          # breaks processed per tick (fixed jit shape)
 
-# buffer donation updates the state in place on accelerators; XLA:CPU
-# does not implement it and would warn on every call
-_DONATE = (0,) if jax.default_backend() != "cpu" else ()
+# the state argument is donated on every backend, so it updates in place
+_DONATE = (0,)
 
 
 class FusedTickState(NamedTuple):
@@ -652,7 +651,6 @@ def _make_mesh_programs(mesh, users_axis: str, p_min: int, border_cap: int,
     gather at edge-fleet sizes), so the body needs no collectives at
     all: one SPMD program serves every device, and churn — which changes
     task-list *content*, never shapes — re-traces nothing."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     ps_u = P(users_axis)        # leading dim sharded over the population
@@ -695,20 +693,20 @@ def _make_mesh_programs(mesh, users_axis: str, p_min: int, border_cap: int,
         COMPILE_COUNTS["mesh_flush"] += 1
         return _flush_impl(state, static, deaths, n_deaths, alpha)
 
-    tick = jax.jit(shard_map(
+    tick = jax.jit(jax.shard_map(
         tick_body, mesh=mesh,
         in_specs=(ps_u, static_spec, ps_u, ps_r, ps_r, ps_r, ps_r,
                   ps_r, ps_r, ps_r, ps_r, ps_u, ps_u),
-        out_specs=ps_u, check_rep=False), donate_argnums=_DONATE)
-    traffic = jax.jit(shard_map(
+        out_specs=ps_u, check_vma=False), donate_argnums=_DONATE)
+    traffic = jax.jit(jax.shard_map(
         traffic_body, mesh=mesh,
         in_specs=(ps_u, static_spec, ps_r, ps_r, ps_u, ps_u,
                   ps_u, ps_u, ps_u, ps_u, ps_u, ps_u, ps_u, ps_r, ps_r),
-        out_specs=ps_u, check_rep=False), donate_argnums=_DONATE)
-    flush = jax.jit(shard_map(
+        out_specs=ps_u, check_vma=False), donate_argnums=_DONATE)
+    flush = jax.jit(jax.shard_map(
         flush_body, mesh=mesh,
         in_specs=(ps_u, static_spec, ps_r, ps_r, ps_r),
-        out_specs=ps_u, check_rep=False), donate_argnums=_DONATE)
+        out_specs=ps_u, check_vma=False), donate_argnums=_DONATE)
     return MeshPrograms(tick=tick, traffic=traffic, flush=flush)
 
 

@@ -125,9 +125,21 @@ def _part1by1(v: np.ndarray) -> np.ndarray:
 
 
 def _quantize(x: np.ndarray, lo: float, hi: float, bits: int) -> np.ndarray:
-    q = np.floor((np.asarray(x, np.float64) - lo) / (hi - lo)
-                 * float(1 << bits)).astype(np.int64)
-    return np.clip(q, 0, (1 << bits) - 1)
+    """Cell index of ``x`` after ``bits`` halvings of ``[lo, hi)``: the
+    same float64 midpoints and ``>=`` comparisons as ``encode``'s
+    bisection, so the two agree even where ``x - lo`` rounds onto a cell
+    edge (e.g. longitudes just below 0)."""
+    x = np.asarray(x, np.float64)
+    lo = np.full(x.shape, lo, np.float64)
+    hi = np.full(x.shape, hi, np.float64)
+    q = np.zeros(x.shape, np.int64)
+    for _ in range(bits):
+        mid = (lo + hi) / 2
+        up = x >= mid
+        q = (q << 1) | up
+        lo = np.where(up, mid, lo)
+        hi = np.where(up, hi, mid)
+    return q
 
 
 def encode_batch(lats, lons, precision: int = 9) -> np.ndarray:

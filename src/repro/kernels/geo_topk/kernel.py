@@ -4,8 +4,9 @@ One grid step scores a (BU,)-user tile against a node tile:
 
 * haversine + 1/(1+d/10) proximity on the VPU (fp32 elementwise over the
   (BU, N) tile);
-* net affinity as a (BU, M) one-hot x (M, N) affinity-column matmul on
-  the MXU (M = net types padded to 8, so the K dim is tile-aligned);
+* net affinity as each user's row of the (M, N) affinity table, picked
+  by M selects (``ref.affinity``: exact, where a one-hot matmul on the
+  MXU would round the table through bf16);
 * the paper's adaptive-precision geohash filter on 20-bit Morton codes —
   int32 compares + row reductions, no int64 on the TPU;
 * iterative max-extract top-k (k is static and small, the loop unrolls);
@@ -14,8 +15,8 @@ One grid step scores a (BU,)-user tile against a node tile:
 Two layouts share the scoring math:
 
 * ``geo_topk_pallas`` — 1-D grid over user tiles, ALL nodes broadcast to
-  each step.  The (BU, N) working set stays in VMEM (BU=128 x N=4096
-  fp32 is 2 MB/matrix — see ``vmem_bytes``), which caps it at N ≲ 16k.
+  each step.  The (BU, N) working set stays in VMEM (see
+  ``vmem_bytes``), which caps it at N ≈ 10k for BU=128.
 * ``geo_topk_tiled_pallas`` — 2-D grid (user tiles x node tiles): node
   blocks of ``node_tile`` stream HBM→VMEM while a running top-k carry
   (scores + global indices) lives in fp32/int32 scratch across the
@@ -38,8 +39,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.geo_topk.ref import (NEG, PREFIX_CHARS, W_AFFINITY,
-                                        W_PROXIMITY, W_RESOURCE,
+                                        W_PROXIMITY, W_RESOURCE, affinity,
                                         haversine_km)
+
+
+# scoped-VMEM limit each kernel is compiled with (above the compiler's
+# default; a v5e core has 128 MiB).  ``tune`` admits only layouts whose
+# ``vmem_bytes``/``vmem_bytes_tiled`` stay inside it
+VMEM_LIMIT_BYTES = 32 * 2**20
+
+
+def _compiler_params(dims, interpret: bool) -> dict:
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=dims, vmem_limit_bytes=VMEM_LIMIT_BYTES)}
 
 
 def _geo_topk_kernel(ulat_ref, ulon_ref, unet_ref, ucode_ref,
@@ -53,7 +68,7 @@ def _geo_topk_kernel(ulat_ref, ulon_ref, unet_ref, ucode_ref,
     nlon = nlon_ref[0:1, :]
     nfree = nfree_ref[0:1, :]
     ncode = ncode_ref[0:1, :]                     # (1, N) int32
-    valid = nvalid_ref[0:1, :] > 0                # (1, N)
+    valid = (nvalid_ref[0:1, :] > 0).astype(jnp.int32)   # (1, N) 0/1
 
     bu = ulat.shape[0]
 
@@ -61,26 +76,23 @@ def _geo_topk_kernel(ulat_ref, ulon_ref, unet_ref, ucode_ref,
     d = haversine_km(ulat, ulon, nlat, nlon)      # (BU,1) x (1,N)
     prox = 1.0 / (1.0 + d / 10.0)                 # (BU, N)
 
-    # ---- affinity term (MXU): one-hot(users) @ per-node affinity columns
-    m = naff_ref.shape[0]
-    onehot = (unet == jax.lax.broadcasted_iota(jnp.int32, (bu, m), 1)
-              ).astype(jnp.float32)
-    aff = jax.lax.dot_general(onehot, naff_ref[...], (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    # ---- affinity term: each user's row of the per-node affinity table
+    aff = affinity(unet, naff_ref[...])
 
     scores = W_RESOURCE * nfree + W_AFFINITY * aff + W_PROXIMITY * prox
 
-    # ---- adaptive-precision geohash filter (int32 prefix compares)
+    # ---- adaptive-precision geohash filter (int32 prefix compares).  The
+    #      masks are int32 0/1: Mosaic cannot select or broadcast i1 values
     local = jnp.broadcast_to(valid, (bu, valid.shape[1]))
-    done = jnp.zeros((bu, 1), bool)
+    done = jnp.zeros((bu, 1), jnp.int32)
     for p in range(PREFIX_CHARS, 0, -1):
         shift = 5 * (PREFIX_CHARS - p)
-        eq = ((ucode >> shift) == (ncode >> shift)) & valid
-        use = (jnp.sum(eq.astype(jnp.int32), axis=1, keepdims=True)
-               >= need) & ~done
-        local = jnp.where(use, eq, local)
+        eq = jnp.where((ucode >> shift) == (ncode >> shift), valid, 0)
+        use = jnp.where(jnp.sum(eq, axis=1, keepdims=True) >= need,
+                        1 - done, 0)
+        local = jnp.where(use > 0, eq, local)
         done = done | use
-    scores = jnp.where(local, scores, jnp.float32(NEG))
+    scores = jnp.where(local > 0, scores, jnp.float32(NEG))
 
     # ---- top-k by repeated max extraction (ties -> lowest index)
     iota = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -100,7 +112,7 @@ def _pad_query(user_lat, user_lon, user_net, user_code20,
                node_lat, node_lon, node_free, node_aff, node_code20,
                node_valid, pu: int, pn: int):
     """Shared pad/reshape prologue: users -> (U+pu, 1) columns, nodes ->
-    (1, N+pn) rows, affinity rows padded to an 8-multiple K dim."""
+    (1, N+pn) rows, affinity rows padded to a whole sublane tile (8)."""
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     ul = jnp.pad(f32(user_lat), (0, pu)).reshape(-1, 1)
@@ -154,17 +166,27 @@ def geo_topk_pallas(user_lat, user_lon, user_net, user_code20,
         out_shape=[jax.ShapeDtypeStruct((up, k), jnp.float32),
                    jax.ShapeDtypeStruct((up, k), jnp.int32)],
         interpret=interpret,
+        **_compiler_params(("parallel",), interpret),
     )(ul, uo, un, uc, nl, no, nf, na, nc, nv)
     return scores[:u], idx[:u]
 
 
+def _work_bytes(block_u: int, width: int) -> int:
+    """Scoped VMEM the TPU compiler allocates for one grid step's
+    (block_u, width) temporaries, bounded from above.  Bisecting
+    ``vmem_limit_bytes`` on v5e (block_u 64..256, widths 512..10240)
+    found about 0.5 MiB fixed plus ~22 fp32 (block_u, width) matrices
+    live at widths up to 1024 lanes, and at most 6 at wider rows."""
+    live = 24 if width <= 1024 else 6
+    return 2**20 + live * block_u * width * 4
+
+
 def vmem_bytes(block_u: int, n: int, k: int = 8, m: int = 8) -> int:
-    """Static VMEM budget for one grid step (fp32 everywhere)."""
+    """Scoped VMEM one untiled grid step needs (fp32 everywhere)."""
     user_tiles = 4 * block_u * 4
     node_tiles = (5 + m) * n * 4
-    work = 5 * block_u * n * 4            # d/prox/aff/scores/local+iota
     out = 2 * block_u * k * 4
-    return 2 * (user_tiles + node_tiles + out) + work
+    return 2 * (user_tiles + node_tiles + out) + _work_bytes(block_u, n)
 
 
 # ---------------------------------------------------------------------------
@@ -227,11 +249,7 @@ def _geo_topk_tiled_kernel(ulat_ref, ulon_ref, unet_ref, ucode_ref,
 
     d = haversine_km(ulat, ulon, nlat, nlon)
     prox = 1.0 / (1.0 + d / 10.0)
-    m = naff_ref.shape[0]
-    onehot = (unet == jax.lax.broadcasted_iota(jnp.int32, (bu, m), 1)
-              ).astype(jnp.float32)
-    aff = jax.lax.dot_general(onehot, naff_ref[...], (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    aff = affinity(unet, naff_ref[...])
     scores = W_RESOURCE * nfree + W_AFFINITY * aff + W_PROXIMITY * prox
 
     # per-user precision chosen from the global count pass; shift == 20
@@ -291,10 +309,7 @@ def geo_topk_tiled_pallas(user_lat, user_lon, user_net, user_code20,
     user_spec = pl.BlockSpec((bu, 1), lambda i, j: (i, 0))
     node_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
     out_spec = pl.BlockSpec((bu, k), lambda i, j: (i, 0))
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    kwargs = _compiler_params(("parallel", "arbitrary"), interpret)
 
     # pass 1: global per-precision hit counts (the adaptive filter decides
     # on totals over ALL nodes, which no single tile can see)
@@ -335,10 +350,10 @@ def geo_topk_tiled_pallas(user_lat, user_lon, user_net, user_code20,
 
 def vmem_bytes_tiled(block_u: int, node_tile: int, k: int = 8,
                      m: int = 8) -> int:
-    """Static VMEM budget for one tiled grid step — independent of N."""
+    """Scoped VMEM one tiled grid step needs — independent of N."""
     user_tiles = 5 * block_u * 4
     node_tiles = (5 + m) * node_tile * 4
-    work = 5 * block_u * node_tile * 4
     carry = 2 * block_u * k * 4            # running top-k scratch
     out = 2 * block_u * k * 4
-    return 2 * (user_tiles + node_tiles + out) + work + carry
+    return (2 * (user_tiles + node_tiles + out) + carry
+            + _work_bytes(block_u, node_tile))

@@ -1,5 +1,9 @@
 """Public fused geo-selection op: Pallas on TPU, jnp oracle elsewhere.
 
+The kernel runs through the Pallas interpreter only when a caller asks
+for it (``interpret=True``); ``force_pallas`` off the TPU without it
+raises rather than interpret in silence.
+
 ``pack_inputs`` flattens a (users, replicas) query into the dtype-correct
 arrays both backends consume (``pack_user_inputs`` / ``pack_node_inputs``
 split the two halves so callers with a static replica set can cache the
@@ -89,8 +93,7 @@ def _dispatch(packed: GeoTopKInputs, k: int, need: int, force_pallas: bool,
               interpret: bool, block_u: Optional[int],
               node_tile: Optional[int]):
     if force_pallas or jax.default_backend() == "tpu":
-        kw = dict(k=k, need=need,
-                  interpret=interpret or jax.default_backend() != "tpu")
+        kw = dict(k=k, need=need, interpret=interpret)
         if block_u is not None:
             kw["block_u"] = block_u
         if node_tile is not None:
@@ -142,10 +145,15 @@ def geo_topk(packed: GeoTopKInputs, *, k: int, need: int = None,
     n = len(packed.node_lat)
     if need is None:
         need = min(MIN_PROXIMITY_HITS, n)
+    on_tpu = jax.default_backend() == "tpu"
+    if force_pallas and not (on_tpu or interpret):
+        raise RuntimeError(
+            "geo_topk: the Pallas kernel compiles for a TPU only, and the "
+            f"backend is {jax.default_backend()!r}; pass interpret=True "
+            "to run it through the Pallas interpreter")
     # consult the autotune cache only when the caller pinned NEITHER
     # knob — an explicit node_tile (or block_u) is a layout request
-    if (force_pallas or jax.default_backend() == "tpu") \
-            and block_u is None and node_tile is None:
+    if (force_pallas or on_tpu) and block_u is None and node_tile is None:
         from repro.kernels.geo_topk import tune
         block_u, node_tile = tune.get_config(len(packed.user_lat), n, k)
     return _dispatch(packed, k, need, force_pallas, interpret, block_u,

@@ -31,6 +31,26 @@ EARTH_KM = 6371.0
 NEG = -1e30
 
 
+# Cephes ``asinf`` minimax coefficients: asin(x) ~ x + x*z*P(z), z = x^2,
+# on [0, 0.5]; larger arguments use asin(x) = pi/2 - 2*asin(sqrt((1-x)/2))
+_ASIN_P = (4.2163199048e-2, 2.4181311049e-2, 4.5470025998e-2,
+           7.4953002686e-2, 1.6666752422e-1)
+
+
+def asin_unit(x):
+    """fp32 ``arcsin`` on [0, 1] from ``sqrt`` and a polynomial — the
+    operations Mosaic lowers (it has no ``asin``/``atan2``).  Error is
+    within a few fp32 ulps of ``np.arcsin`` over the whole range."""
+    big = x > 0.5
+    z = jnp.where(big, 0.5 * (1.0 - x), x * x)
+    r = jnp.where(big, jnp.sqrt(z), x)
+    poly = _ASIN_P[0]
+    for c in _ASIN_P[1:]:
+        poly = poly * z + c
+    p = r + r * z * poly
+    return jnp.where(big, jnp.float32(jnp.pi / 2) - 2.0 * p, p)
+
+
 def haversine_km(ulat, ulon, nlat, nlon):
     """Broadcasted fp32 haversine: (U, 1) x (1, N) -> (U, N)."""
     rad = jnp.float32(jnp.pi / 180.0)
@@ -40,7 +60,18 @@ def haversine_km(ulat, ulon, nlat, nlon):
     dl = (nlon - ulon) * rad
     a = (jnp.sin(dp / 2) ** 2
          + jnp.cos(p1) * jnp.cos(p2) * jnp.sin(dl / 2) ** 2)
-    return 2.0 * EARTH_KM * jnp.arcsin(jnp.sqrt(jnp.clip(a, 0.0, 1.0)))
+    return 2.0 * EARTH_KM * asin_unit(jnp.sqrt(jnp.clip(a, 0.0, 1.0)))
+
+
+def affinity(user_net, node_aff):
+    """(U, 1) int32 net index x (M, N) affinity rows -> (U, N): each
+    user's own row, picked by selects.  Exact on every backend — a
+    one-hot matmul would round the table through bf16 on the TPU's MXU
+    at default precision."""
+    aff = jnp.zeros((user_net.shape[0], node_aff.shape[1]), jnp.float32)
+    for r in range(node_aff.shape[0]):
+        aff = jnp.where(user_net == r, node_aff[r:r + 1, :], aff)
+    return aff
 
 
 def _raw_scores(user_lat, user_lon, user_net, node_lat, node_lon,
@@ -51,11 +82,7 @@ def _raw_scores(user_lat, user_lon, user_net, node_lat, node_lon,
     d = haversine_km(user_lat[:, None], user_lon[:, None],
                      node_lat[None, :], node_lon[None, :])
     prox = 1.0 / (1.0 + d / 10.0)
-    m = node_aff.shape[0]
-    onehot = (user_net[:, None]
-              == lax.broadcasted_iota(jnp.int32, (user_net.shape[0], m), 1)
-              ).astype(jnp.float32)
-    aff = onehot @ node_aff                            # (U, N)
+    aff = affinity(user_net[:, None], node_aff)        # (U, N)
     return (W_RESOURCE * node_free[None, :] + W_AFFINITY * aff
             + W_PROXIMITY * prox)
 

@@ -7,8 +7,9 @@ scheme the attention kernels use for their block sizes — and caches the
 winner so ``ops.geo_topk`` picks it up transparently:
 
 * ``candidate_configs(u, n, k)`` enumerates ``(block_u, node_tile)``
-  pairs whose static VMEM budget fits (``node_tile=None`` means the
-  untiled kernel, admissible only while ``vmem_bytes`` fits);
+  pairs whose VMEM need fits the kernels' scoped limit
+  (``kernel.VMEM_LIMIT_BYTES``; ``node_tile=None`` means the untiled
+  kernel, admissible only while ``vmem_bytes`` fits);
 * ``autotune(u, n, k)`` times each config on synthetic inputs shaped
   like the query, stores the best per ``(backend, bucket(u), bucket(n),
   k)`` and returns the full timing table;
@@ -31,15 +32,13 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.kernels.geo_topk.kernel import (geo_topk_pallas,
+from repro.kernels.geo_topk.kernel import (VMEM_LIMIT_BYTES, geo_topk_pallas,
                                            geo_topk_tiled_pallas, vmem_bytes,
                                            vmem_bytes_tiled)
 
-# half a v5e VMEM — the budget the kernel tests pin
-VMEM_BUDGET = 64 * 2**20
-
 BLOCK_U_CANDIDATES = (64, 128, 256)
 NODE_TILE_CANDIDATES = (512, 1024, 2048, 4096, 8192)
+DEFAULT_NODE_TILE = 2048
 
 Config = Tuple[int, Optional[int]]          # (block_u, node_tile|None)
 
@@ -62,19 +61,24 @@ def cache_key(u: int, n: int, k: int) -> Tuple:
     return (_backend(), _bucket(u), _bucket(n), k)
 
 
-def candidate_configs(u: int, n: int, k: int,
-                      *, budget: int = VMEM_BUDGET) -> List[Config]:
+def _fits(cfg: Config, n: int, k: int) -> bool:
+    bu, nt = cfg
+    need = vmem_bytes(bu, n, k) if nt is None else vmem_bytes_tiled(bu, nt, k)
+    return need <= VMEM_LIMIT_BYTES
+
+
+def candidate_configs(u: int, n: int, k: int) -> List[Config]:
     """VMEM-admissible (block_u, node_tile) pairs for a (U, N, k) query."""
     out: List[Config] = []
     for bu in BLOCK_U_CANDIDATES:
         if bu > max(8, _bucket(u)):
             continue
-        if vmem_bytes(bu, n, k) < budget:
+        if _fits((bu, None), n, k):
             out.append((bu, None))
         for nt in NODE_TILE_CANDIDATES:
             if nt >= n or nt < k:
                 continue                 # tiling only pays below N
-            if vmem_bytes_tiled(bu, nt, k) < budget:
+            if _fits((bu, nt), n, k):
                 out.append((bu, nt))
     if not out:                          # degenerate shapes: smallest tile
         out.append((min(BLOCK_U_CANDIDATES), min(NODE_TILE_CANDIDATES)))
@@ -83,26 +87,21 @@ def candidate_configs(u: int, n: int, k: int,
 
 def default_config(u: int, n: int, k: int) -> Config:
     """Heuristic used when nothing was tuned: untiled while it fits the
-    VMEM budget, else the largest admissible node tile."""
-    if vmem_bytes(128, n, k) < VMEM_BUDGET:
+    scoped VMEM limit, else 2,048-node tiles.  Compile time grows with
+    the tile (for v5e: 8 s at 128 x 2,048, 54 s at 128 x 8,192), and
+    ranking the tiles by speed is ``autotune``'s job."""
+    if _fits((128, None), n, k):
         return (128, None)
-    for nt in reversed(NODE_TILE_CANDIDATES):
-        if vmem_bytes_tiled(128, nt, k) < VMEM_BUDGET:
-            return (128, nt)
-    return (64, NODE_TILE_CANDIDATES[0])
+    return (128, DEFAULT_NODE_TILE)
 
 
 def get_config(u: int, n: int, k: int) -> Config:
     """Cached winner for the shape bucket, re-checked against THIS
-    query's VMEM budget (a winner tuned at the small end of a bucket may
+    query's VMEM need (a winner tuned at the small end of a bucket may
     not be admissible at the large end), else the heuristic default."""
     cfg = _CACHE.get(cache_key(u, n, k))
-    if cfg is not None:
-        bu, nt = cfg
-        fits = vmem_bytes(bu, n, k) < VMEM_BUDGET if nt is None \
-            else vmem_bytes_tiled(bu, nt, k) < VMEM_BUDGET
-        if fits:
-            return cfg
+    if cfg is not None and _fits(cfg, n, k):
+        return cfg
     return default_config(u, n, k)
 
 
@@ -140,17 +139,15 @@ def autotune(u: int, n: int, k: int = 8, *, need: int = 4,
 
     ``interpret=True`` runs the kernels through the Pallas interpreter —
     functional end-to-end on CPU (the tier-1 smoke path), with timings
-    that only rank Python-level work.
+    that only rank Python-level work.  A config that fails to compile or
+    run raises: every candidate is meant to fit the chip.
     """
     packed = _synthetic_inputs(u, n, seed=seed)
     configs = candidate_configs(u, n, k) if configs is None else configs
     timings: Dict[Config, float] = {}
     for cfg in configs:
-        try:
-            s, i = _run_config(packed, cfg, k, need, interpret)
-            s.block_until_ready()            # compile outside the clock
-        except Exception:                    # config unsupported on backend
-            continue
+        s, i = _run_config(packed, cfg, k, need, interpret)
+        s.block_until_ready()                # compile outside the clock
         best = float("inf")
         for _ in range(repeats):
             t0 = time.perf_counter()
@@ -158,8 +155,6 @@ def autotune(u: int, n: int, k: int = 8, *, need: int = 4,
             s.block_until_ready()
             best = min(best, (time.perf_counter() - t0) * 1e3)
         timings[cfg] = best
-    if not timings:
-        raise RuntimeError(f"no geo_topk config ran for U={u} N={n} k={k}")
     winner = min(timings, key=timings.get)
     _CACHE[cache_key(u, n, k)] = winner
     return {"best": winner, "timings_ms": timings}

@@ -4,11 +4,8 @@ auto-scaling, and multi-connection failover."""
 import numpy as np
 import pytest
 
-try:                              # hypothesis is a dev-only dependency —
-    from hypothesis import given, settings          # requirements-dev.txt
-    from hypothesis import strategies as st
-except ModuleNotFoundError:       # clean env: deterministic sampling shim
-    from tests._hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import geohash
 from repro.core.app_manager import ServiceSpec, Task

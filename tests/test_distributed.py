@@ -8,11 +8,8 @@ import subprocess
 import sys
 
 import pytest
-try:                              # hypothesis is a dev-only dependency —
-    from hypothesis import given, settings          # requirements-dev.txt
-    from hypothesis import strategies as st
-except ModuleNotFoundError:       # clean env: deterministic sampling shim
-    from tests._hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as PS
 
 from repro.config import MULTI_POD, SHAPES, SINGLE_POD

@@ -1,5 +1,5 @@
 """Property-based tests for the batched geo primitives the shard router
-silently relies on (hypothesis, or the deterministic fallback shim).
+silently relies on (hypothesis).
 
 The Beacon fault-domain router assumes three invariants of
 ``repro.core.geohash``:
@@ -17,11 +17,8 @@ The Beacon fault-domain router assumes three invariants of
 """
 import numpy as np
 
-try:                              # hypothesis is a dev-only dependency —
-    from hypothesis import given, settings          # requirements-dev.txt
-    from hypothesis import strategies as st
-except ModuleNotFoundError:       # clean env: deterministic sampling shim
-    from tests._hypothesis_fallback import given, settings, st
+from hypothesis import example, given, settings
+from hypothesis import strategies as st
 
 from repro.core import geohash
 
@@ -30,6 +27,7 @@ lon_st = st.floats(min_value=-179.9, max_value=179.9, allow_nan=False)
 
 
 @given(lat=lat_st, lon=lon_st, p=st.integers(min_value=1, max_value=9))
+@example(lat=0.0, lon=-3.103005580771824e-57, p=1)   # lon + 180 rounds to 180
 @settings(max_examples=100, deadline=None)
 def test_encode_batch_matches_string_encode_and_roundtrips(lat, lon, p):
     """Batch Morton code == string-encoded code, and the decoded cell
@@ -58,6 +56,7 @@ def test_morton_prefix_nesting(lat, lon, p):
 
 
 @given(lat=lat_st, lon=lon_st, p=st.integers(min_value=2, max_value=9))
+@example(lat=0.0, lon=-2.220446049250313e-16, p=2)   # lon + 180 rounds to 180
 @settings(max_examples=50, deadline=None)
 def test_shared_prefix_chars_matches_string_common_prefix(lat, lon, p):
     """The vectorized prefix-length primitive agrees with the string one

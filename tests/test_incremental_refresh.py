@@ -28,11 +28,8 @@ deadline fired.  These tests pin the mode across the tick paths:
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings          # requirements-dev.txt
-    from hypothesis import strategies as st
-except ImportError:                                 # pragma: no cover
-    from tests._hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import repro.core.fused_tick as fused_tick
 from repro.core.app_manager import ServiceSpec, Task

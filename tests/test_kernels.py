@@ -14,7 +14,7 @@ from repro.kernels.geo_topk.kernel import (geo_topk_pallas,
                                            vmem_bytes_tiled)
 from repro.kernels.geo_topk.kernel import vmem_bytes as geo_vmem
 from repro.kernels.geo_topk.ops import geo_topk, pack_inputs
-from repro.kernels.geo_topk.ref import geo_topk_reference
+from repro.kernels.geo_topk.ref import affinity, asin_unit, geo_topk_reference
 from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import mha_reference
@@ -282,6 +282,42 @@ def test_geo_topk_op_dispatches_to_oracle_on_cpu():
     np.testing.assert_array_equal(np.asarray(i_op), np.asarray(i_ref))
     np.testing.assert_allclose(np.asarray(s_op), np.asarray(s_ref),
                                atol=1e-6, rtol=1e-6)
+
+
+def test_geo_topk_force_pallas_off_tpu_needs_interpret():
+    """Off the TPU the kernel runs only through the interpreter, and
+    only when asked: no silent interpretation behind ``force_pallas``."""
+    packed = _geo_inputs(16, 24, seed=11)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        geo_topk(packed, k=3, force_pallas=True)
+
+
+def test_asin_unit_within_fp32_rounding_of_arcsin():
+    """The Mosaic-lowerable arcsin the haversine uses stays within a few
+    fp32 ulps of float64 ``np.arcsin`` over all of [0, 1], the branch
+    point at 0.5 and the far end (antipodal pairs) included."""
+    x = np.concatenate([
+        np.linspace(0.0, 1.0, 400_001, dtype=np.float32),
+        np.nextafter(np.float32(0.5), np.float32([0.0, 1.0])),
+        np.float32([1e-30, 1e-8, 1.0 - 2**-24])])
+    got = np.asarray(asin_unit(jnp.asarray(x)), np.float64)
+    ref = np.arcsin(x.astype(np.float64))
+    ulp = np.spacing(ref.astype(np.float32)).astype(np.float64)
+    assert (np.abs(got - ref) <= 3 * ulp).all()
+
+
+def test_affinity_rows_are_exact():
+    """Each user's affinity row is copied bit for bit from the table
+    (a default-precision matmul on the MXU would round it through
+    bf16)."""
+    from repro.core.selection import AFFINITY_TABLE
+    rng = np.random.default_rng(7)
+    net = rng.integers(0, AFFINITY_TABLE.shape[0], 64)
+    node_net = rng.integers(0, AFFINITY_TABLE.shape[0], 40)
+    table = AFFINITY_TABLE[node_net, :].T.astype(np.float32)   # (M, N)
+    got = np.asarray(affinity(jnp.asarray(net[:, None], jnp.int32),
+                              jnp.asarray(table)))
+    np.testing.assert_array_equal(got, table[net])
 
 
 def test_geo_topk_vmem_budget():

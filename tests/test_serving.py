@@ -7,11 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:                              # hypothesis is a dev-only dependency —
-    from hypothesis import given, settings          # requirements-dev.txt
-    from hypothesis import strategies as st
-except ModuleNotFoundError:       # clean env: deterministic sampling shim
-    from tests._hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.config import reduced
 from repro.configs import get_config
