@@ -75,7 +75,6 @@ notifications replay in warm-connection insertion order.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -84,6 +83,7 @@ import numpy as np
 from repro.core import geohash
 from repro.core.captain import Request
 from repro.core.selection import net_index
+from repro.core.spans import Spans
 
 # Step-1 wide candidate list size: baselines filter the WIDE list before
 # trimming to TopN, so a "dedicated-only" client can't leak onto volunteer
@@ -675,17 +675,15 @@ class ClientPool:
             self._lat_edges = np.concatenate(
                 [[0.0], np.logspace(0.0, 5.0, 230), [np.inf]])
             self._lat_hist = np.zeros(self._lat_edges.size - 1, np.int64)
-        # per-phase wall time (ms) accumulated across ticks, so benchmark
-        # runs can attribute where a tick goes (selection / policy /
-        # transport on the host tick; fused_tick / transport on device)
+        # per-span wall time (ms) and counters accumulated across ticks,
+        # so benchmark runs can attribute where a tick goes (selection /
+        # policy / transport on the host tick; fused_tick / transport and
+        # their dotted children on device) and what it moves
         self.phase_ms: Dict[str, float] = {}
+        self.counts: Dict[str, int] = {}
+        self.spans = Spans(self.phase_ms, self.counts)
 
     # ------------------------------------------------------------- control
-
-    def phase_add(self, name: str, t0: float) -> None:
-        """Accumulate wall time since ``t0`` under phase ``name``."""
-        self.phase_ms[name] = self.phase_ms.get(name, 0.0) \
-            + (time.perf_counter() - t0) * 1e3
 
     def start(self):
         """Start every user (one simulator event; schedule with
@@ -1235,38 +1233,35 @@ class ClientPool:
 
     def _tick_fluid(self, first: bool = False):
         now = self.sim.now
-        t0 = time.perf_counter()
-        self._flush_fluid()
-        self.phase_add("policy", t0)
+        span = self.spans.span
+        with span("policy"):
+            self._flush_fluid()
         sel = np.nonzero(self.running & self.ticking)[0]
         if sel.size:
             if not first:
                 if self._rt is not None:
-                    t0 = time.perf_counter()
-                    dirty = self._rt.dirty_mask(now)
-                    self.phase_add("refresh_track", t0)
+                    with span("refresh_track"):
+                        dirty = self._rt.dirty_mask(now)
                 else:
                     dirty = None
-                t0 = time.perf_counter()
-                r_ok = self._discovery_refresh_mask()
-                r_sel = sel if r_ok is None else sel[r_ok[sel]]
-                if dirty is not None:
-                    # incremental: refresh only the dirty subset (the
-                    # discovery gate above composes by AND — a deferred
-                    # user stays marked and refreshes when it opens)
-                    r_sel = r_sel[dirty[r_sel]]
-                    self._rt.dirty_counts.append(int(r_sel.size))
-                if r_sel.size:
-                    self._refresh(r_sel)
+                with span("selection"):
+                    r_ok = self._discovery_refresh_mask()
+                    r_sel = sel if r_ok is None else sel[r_ok[sel]]
                     if dirty is not None:
-                        self._rt.note_refreshed(r_sel, now)
-                self.phase_add("selection", t0)
-            t0 = time.perf_counter()
-            self._switch_step(sel)
-            self.phase_add("policy", t0)
-            t0 = time.perf_counter()
-            self._traffic_fluid(sel, now)
-            self.phase_add("transport", t0)
+                        # incremental: refresh only the dirty subset (the
+                        # discovery gate above composes by AND — a
+                        # deferred user stays marked and refreshes when
+                        # it opens)
+                        r_sel = r_sel[dirty[r_sel]]
+                        self._rt.dirty_counts.append(int(r_sel.size))
+                    if r_sel.size:
+                        self._refresh(r_sel)
+                        if dirty is not None:
+                            self._rt.note_refreshed(r_sel, now)
+            with span("policy"):
+                self._switch_step(sel)
+            with span("transport"):
+                self._traffic_fluid(sel, now)
             self.ticks_run += 1
         if (self.running & self.ticking).any():
             self.sim.after(self.probe_period, self._tick_fluid)

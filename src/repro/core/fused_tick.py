@@ -57,7 +57,6 @@ bookkeeping, and metric mirrors.
 from __future__ import annotations
 
 import collections
-import time
 from typing import List, NamedTuple, Optional
 
 import jax
@@ -407,31 +406,12 @@ def _sharded_candidates_sparse(static, free, sched, need, k, p_min,
     return cand, b_count > border_cap
 
 
-def _tick_impl(state, static, free, sched, alive, need, deaths, n_deaths,
-               alpha, margin, refresh_ok, dirty, p_min, border_cap,
-               refresh_cap):
-    COMPILE_COUNTS["tick"] += 1
-    u, k = state.cand.shape
-    rows = jnp.arange(u)
-    tn = static.task_node
-
-    # 1. queued connection breaks (before the fold — host breaks happen
-    #    mid-window, after traffic was scheduled but before it is folded)
-    enodes, evals, cand, active, reinit, failovers = _process_deaths(
-        state, tn, deaths, n_deaths)
-
-    # 2. fold the previous window
-    enodes, evals, overflow, fc, fs = _fold_window(
-        state, enodes, evals, tn, alpha)
-
-    # 3. candidate refresh: fused scoring + top-k (lax.top_k — the exact
-    #    op the geo_topk kernel path dispatches to, same min-index ties) —
-    #    one (U, Tp) pass unsharded, or per-shard (U_s, Ts_pad) passes
-    #    plus the fixed-capacity border pass when the engine is sharded.
-    #    ``refresh_ok`` gates the refresh only: users inside a Beacon
-    #    re-discovery window keep (and keep probing) their stale
-    #    candidates, exactly like the host tick's filtered ``_refresh``
-    tick_mask = state.running & state.ticking
+def _refresh_candidates(static, cand, reinit, tick_mask, free, sched, need,
+                        refresh_ok, dirty, p_min, border_cap,
+                        refresh_cap):
+    """Step 3 of the tick, the candidate refresh: returns
+    ``(cand, border_overflow, refresh_fallback)``."""
+    u, k = cand.shape
     if refresh_cap == 0:
         # every-tick refresh (the historical semantics, bit-for-bit)
         refresh_mask = tick_mask & refresh_ok
@@ -507,40 +487,76 @@ def _tick_impl(state, static, free, sched, alive, need, deaths, n_deaths,
         cand, border_overflow = jax.lax.cond(over, dense_fn, sparse_fn,
                                              cand)
         refresh_fallback = over
+    return cand, border_overflow, refresh_fallback
+
+
+def _tick_impl(state, static, free, sched, alive, need, deaths, n_deaths,
+               alpha, margin, refresh_ok, dirty, p_min, border_cap,
+               refresh_cap):
+    COMPILE_COUNTS["tick"] += 1
+    u, k = state.cand.shape
+    rows = jnp.arange(u)
+    tn = static.task_node
+
+    # 1. queued connection breaks (before the fold — host breaks happen
+    #    mid-window, after traffic was scheduled but before it is folded)
+    with jax.named_scope("deaths"):
+        enodes, evals, cand, active, reinit, failovers = _process_deaths(
+            state, tn, deaths, n_deaths)
+
+    # 2. fold the previous window
+    with jax.named_scope("fold"):
+        enodes, evals, overflow, fc, fs = _fold_window(
+            state, enodes, evals, tn, alpha)
+
+    # 3. candidate refresh: fused scoring + top-k (lax.top_k — the exact
+    #    op the geo_topk kernel path dispatches to, same min-index ties) —
+    #    one (U, Tp) pass unsharded, or per-shard (U_s, Ts_pad) passes
+    #    plus the fixed-capacity border pass when the engine is sharded.
+    #    ``refresh_ok`` gates the refresh only: users inside a Beacon
+    #    re-discovery window keep (and keep probing) their stale
+    #    candidates, exactly like the host tick's filtered ``_refresh``
+    tick_mask = state.running & state.ticking
+    with jax.named_scope("refresh"):
+        cand, border_overflow, refresh_fallback = _refresh_candidates(
+            static, cand, reinit, tick_mask, free, sched, need, refresh_ok,
+            dirty, p_min, border_cap, refresh_cap)
 
     # users who lost every candidate re-enter initial selection: active
     # is the best-base-RTT candidate (Client start semantics)
-    base = jnp.where(cand >= 0, _base_rtt(static, cand), jnp.inf)
-    init_slot = jnp.argmin(base, axis=1)
-    has_cand = (cand >= 0).any(axis=1)
-    init_active = jnp.where(has_cand, cand[rows, init_slot], -1)
-    do_init = reinit & tick_mask
-    active = jnp.where(do_init, init_active, active)
-    reinit = jnp.where(do_init & has_cand, False, reinit)
+    with jax.named_scope("switch"):
+        base = jnp.where(cand >= 0, _base_rtt(static, cand), jnp.inf)
+        init_slot = jnp.argmin(base, axis=1)
+        has_cand = (cand >= 0).any(axis=1)
+        init_active = jnp.where(has_cand, cand[rows, init_slot], -1)
+        do_init = reinit & tick_mask
+        active = jnp.where(do_init, init_active, active)
+        reinit = jnp.where(do_init & has_cand, False, reinit)
 
-    # 4. two-round confirmed switch on the freshly folded EMAs.  The
-    #    pending target is judged from the EMA table + task-alive mask
-    #    directly (not via candidate-list membership — the candidate set
-    #    rotates under load feedback)
-    cand_node = jnp.where(cand >= 0, tn[jnp.clip(cand, 0)], -1)
-    act_node = jnp.where(active >= 0, tn[jnp.clip(active, 0)], -1)
-    cand_ema = _ema_get_matrix(enodes, evals, cand_node)
-    act_ema = _ema_get(enodes, evals, act_node)
-    pend = state.pending
-    pend_node = jnp.where(pend >= 0, tn[jnp.clip(pend, 0)], -1)
-    pend_ema = _ema_get(enodes, evals, pend_node)
-    pend_alive = (pend >= 0) & alive[jnp.clip(pend, 0)]
-    confirm, target, new_pending = switch_decide(
-        cand, cand_ema, active, act_ema, pend, pend_ema, pend_alive,
-        margin, xp=jnp)
-    confirm = confirm & tick_mask
-    pending = jnp.where(tick_mask, new_pending, state.pending)
-    active = jnp.where(confirm, target, active)
+        # 4. two-round confirmed switch on the freshly folded EMAs.  The
+        #    pending target is judged from the EMA table + task-alive
+        #    mask directly (not via candidate-list membership — the
+        #    candidate set rotates under load feedback)
+        cand_node = jnp.where(cand >= 0, tn[jnp.clip(cand, 0)], -1)
+        act_node = jnp.where(active >= 0, tn[jnp.clip(active, 0)], -1)
+        cand_ema = _ema_get_matrix(enodes, evals, cand_node)
+        act_ema = _ema_get(enodes, evals, act_node)
+        pend = state.pending
+        pend_node = jnp.where(pend >= 0, tn[jnp.clip(pend, 0)], -1)
+        pend_ema = _ema_get(enodes, evals, pend_node)
+        pend_alive = (pend >= 0) & alive[jnp.clip(pend, 0)]
+        confirm, target, new_pending = switch_decide(
+            cand, cand_ema, active, act_ema, pend, pend_ema, pend_alive,
+            margin, xp=jnp)
+        confirm = confirm & tick_mask
+        pending = jnp.where(tick_mask, new_pending, state.pending)
+        active = jnp.where(confirm, target, active)
 
-    # 5. next-window traffic: probes to every live candidate, frames to
-    #    the live active
-    probe_ok = (cand >= 0) & alive[jnp.clip(cand, 0)] & tick_mask[:, None]
-    frame_ok = (active >= 0) & alive[jnp.clip(active, 0)] & tick_mask
+        # 5. next-window traffic: probes to every live candidate, frames
+        #    to the live active
+        probe_ok = (cand >= 0) & alive[jnp.clip(cand, 0)] \
+            & tick_mask[:, None]
+        frame_ok = (active >= 0) & alive[jnp.clip(active, 0)] & tick_mask
 
     nf = state.lat_frame.shape[1]
     new_state = FusedTickState(
@@ -608,10 +624,12 @@ def _flush_impl(state, static, deaths, n_deaths, alpha):
     u, k = state.cand.shape
     nf = state.lat_frame.shape[1]
     tn = static.task_node
-    enodes, evals, cand, active, reinit, failovers = _process_deaths(
-        state, tn, deaths, n_deaths)
-    enodes, evals, overflow, fc, fs = _fold_window(
-        state, enodes, evals, tn, alpha)
+    with jax.named_scope("deaths"):
+        enodes, evals, cand, active, reinit, failovers = _process_deaths(
+            state, tn, deaths, n_deaths)
+    with jax.named_scope("fold"):
+        enodes, evals, overflow, fc, fs = _fold_window(
+            state, enodes, evals, tn, alpha)
     return state._replace(
         ema_nodes=enodes, ema_vals=evals, ema_overflow=overflow,
         cand=cand, active=active, reinit=reinit,
@@ -805,10 +823,22 @@ class FusedTickDriver:
         ulat, ulon, unet, ucode = self._packed_user()
         return st, tn, proc, slots, ulat, ulon, unet, ucode
 
+    def _count_static(self, view, st):
+        """Count a static rebuild, and the node arrays the view uploads
+        once per node epoch (``packed_static``)."""
+        spans = self.pool.spans
+        spans.count("static_rebuilds", 1)
+        if view.epoch != self._epoch:
+            spans.count("h2d_bytes", sum(
+                x.nbytes for x in (st.lat, st.lon, st.aff, st.code20,
+                                   st.cloud)))
+
     def _rebuild_static(self, view):
         pool = self.pool
         st, tn, proc, slots, ulat, ulon, unet, ucode = \
             self._host_static_arrays(view)
+        self._count_static(view, st)
+        pool.spans.h2d(ulat, ulon, unet, ucode, tn, proc, slots)
         self.static = FusedTickStatic(
             user_lat=jnp.asarray(ulat), user_lon=jnp.asarray(ulon),
             user_net=jnp.asarray(unet), user_code20=jnp.asarray(ucode),
@@ -850,9 +880,11 @@ class FusedTickDriver:
             user_ix = np.nonzero(u_shard == sh.code)[0]
             if user_ix.size == 0:
                 continue        # border pass covers its nodes if needed
-            entries.append(ShardIx(
-                user_ix=jnp.asarray(user_ix, jnp.int32),
-                task_ix=jnp.asarray(sh.task_ix_padded(self.node_pad))))
+            user_ix = user_ix.astype(np.int32)
+            task_ix = sh.task_ix_padded(self.node_pad)
+            pool.spans.h2d(user_ix, task_ix)
+            entries.append(ShardIx(user_ix=jnp.asarray(user_ix),
+                                   task_ix=jnp.asarray(task_ix)))
         self.p_min = shard_view.precision
         self.border_cap = pool.shard_border_cap \
             if pool.shard_border_cap is not None \
@@ -894,6 +926,7 @@ class FusedTickDriver:
                 f"{len(deaths)} breaks in one window > DEATH_QUEUE_MAX")
         arr = np.full(DEATH_QUEUE_MAX, -1, np.int32)
         arr[:len(deaths)] = deaths
+        self.pool.spans.count("breaks", len(deaths))
         return arr, np.int32(len(deaths))
 
     def _refresh_mask(self):
@@ -915,10 +948,8 @@ class FusedTickDriver:
             if self._no_dirty is None:
                 self._no_dirty = np.zeros(pool.n_users, bool)
             return self._no_dirty
-        t0 = time.perf_counter()
-        dirty = pool._rt.dirty_mask(pool.sim.now)
-        pool.phase_add("refresh_track", t0)
-        return dirty
+        with pool.spans.span("refresh_track"):
+            return pool._rt.dirty_mask(pool.sim.now)
 
     def _note_refreshed(self, dirty, r_ok, outs):
         """Mirror the program's refresh set back into the tracker: clear
@@ -930,7 +961,7 @@ class FusedTickDriver:
         if rt is None:
             return
         refreshed = dirty & pool.running & pool.ticking & r_ok
-        if bool(np.asarray(outs.refresh_fallback).any()):
+        if bool(pool.spans.d2h(outs.refresh_fallback).any()):
             rt.fallbacks += 1
         rt.note_refreshed(refreshed, pool.sim.now)
         rt.dirty_counts.append(int(refreshed.sum()))
@@ -941,13 +972,18 @@ class FusedTickDriver:
         pool = self.pool
         dirty = self._dirty_input()
         r_ok = self._refresh_mask()
-        self.state, outs = _fused_tick(
-            self.state, self.static, free, sched, alive, need, deaths,
-            n_deaths, pool.alpha, pool.switch_margin, r_ok, dirty,
-            p_min=self.p_min, border_cap=self.border_cap,
-            refresh_cap=self.refresh_cap)
+        with pool.spans.span("fused_tick.dispatch"):
+            pool.spans.h2d(free, sched, alive, need, deaths, n_deaths,
+                           r_ok, dirty)
+            self.state, outs = _fused_tick(
+                self.state, self.static, free, sched, alive, need, deaths,
+                n_deaths, pool.alpha, pool.switch_margin, r_ok, dirty,
+                p_min=self.p_min, border_cap=self.border_cap,
+                refresh_cap=self.refresh_cap)
         self._stash_dirty = False       # tick folded the previous window
-        if bool(np.asarray(outs.border_overflow).any()):
+        with pool.spans.span("fused_tick.wait"):
+            border_overflow = pool.spans.d2h(outs.border_overflow)
+        if bool(border_overflow.any()):
             raise RuntimeError(
                 f"fused tick: border band exceeded {self.border_cap} "
                 "users — restart the pool with a larger shard_border_cap "
@@ -957,58 +993,68 @@ class FusedTickDriver:
 
     def tick(self):
         pool = self.pool
-        t0 = time.perf_counter()
-        view = pool._view()
-        engine = pool.am.engine
-        if view.epoch != self._epoch \
-                or engine.owner_version != self._owner_version:
-            # node-epoch change, or a Beacon handoff/re-home re-routed
-            # regions (the transient: shard structure may retrace once)
-            self._rebuild_static(view)
-        free, sched, alive = view.padded_dynamic(
-            self.node_pad, hidden=engine.hidden_nodes,
-            locality=engine.data_locality.get(pool.service_id),
-            queueing=engine.queueing.get(pool.service_id))
-        need = np.int32(min(MIN_PROXIMITY_HITS, int(sched.sum())))
-        deaths, n_deaths = self._drain_deaths()
-        pool.phase_add("transport", t0)
+        span = pool.spans.span
+        with span("transport"):
+            with span("transport.static"):
+                view = pool._view()
+                engine = pool.am.engine
+                if view.epoch != self._epoch \
+                        or engine.owner_version != self._owner_version:
+                    # node-epoch change, or a Beacon handoff/re-home
+                    # re-routed regions (the transient: shard structure
+                    # may retrace once)
+                    self._rebuild_static(view)
+            with span("transport.dynamic"):
+                free, sched, alive = view.padded_dynamic(
+                    self.node_pad, hidden=engine.hidden_nodes,
+                    locality=engine.data_locality.get(pool.service_id),
+                    queueing=engine.queueing.get(pool.service_id))
+                need = np.int32(min(MIN_PROXIMITY_HITS, int(sched.sum())))
+                deaths, n_deaths = self._drain_deaths()
 
-        t0 = time.perf_counter()
-        outs = self._run_tick(free, sched, alive, need, deaths, n_deaths)
-        cand = self._pull(outs.cand)
-        active = self._pull(outs.active)
-        probe_ok = self._pull(outs.probe_ok)
-        frame_ok = self._pull(outs.frame_ok)
-        confirm = self._pull(outs.confirm)
-        pool.phase_add("fused_tick", t0)
+        with span("fused_tick"):
+            outs = self._run_tick(free, sched, alive, need, deaths,
+                                  n_deaths)
+            with span("fused_tick.pull"):
+                cand = self._pull(outs.cand)
+                active = self._pull(outs.active)
+                probe_ok = self._pull(outs.probe_ok)
+                frame_ok = self._pull(outs.frame_ok)
+                confirm = self._pull(outs.confirm)
 
-        t0 = time.perf_counter()
-        # mirrors + switch records (scalar-identical timestamps/order)
-        pool.cand_task = cand
-        pool.active = active
-        pool.pending = self._pull(outs.pending)
-        pool.failovers = int(np.asarray(outs.failovers).sum())
-        self.check_overflow()
-        rows = np.nonzero(confirm)[0]
-        # per-switch records match the host tick's (time, user, from, to)
-        # stream; population-scale runs opt out via record_samples=False
-        # (the host tick has no such toggle — it pays the append cost)
-        if rows.size and pool.record_samples:
-            from_node = self._pull(outs.from_node)
-            now = pool.sim.now
-            for u in rows:
-                pool.switch_t.append(now)
-                pool.switch_user.append(int(u))
-                pool.switch_from.append(
-                    pool._node_ids[int(from_node[u])])
-                pool.switch_to.append(
-                    pool._node_ids[pool.task_node[int(active[u])]])
-        self._send_traffic(cand, active, probe_ok, frame_ok)
-        pool.phase_add("transport", t0)
+        with span("transport"):
+            with span("transport.mirrors"):
+                # mirrors + switch records (scalar-identical
+                # timestamps/order)
+                pool.cand_task = cand
+                pool.active = active
+                pool.pending = self._pull(outs.pending)
+                pool.failovers = int(pool.spans.d2h(outs.failovers).sum())
+                self.check_overflow()
+                self._record_switches(confirm, active, outs)
+            self._send_traffic(cand, active, probe_ok, frame_ok)
 
         if bool((pool.running & pool.ticking).any()):
             pool.ticks_run += 1
             pool.sim.after(pool.probe_period, self.tick)
+
+    def _record_switches(self, confirm, active, outs):
+        """Per-switch records match the host tick's (time, user, from,
+        to) stream; population-scale runs opt out via
+        record_samples=False (the host tick has no such toggle — it pays
+        the append cost)."""
+        pool = self.pool
+        rows = np.nonzero(confirm)[0]
+        if not (rows.size and pool.record_samples):
+            return
+        from_node = self._pull(outs.from_node)
+        now = pool.sim.now
+        for u in rows:
+            pool.switch_t.append(now)
+            pool.switch_user.append(int(u))
+            pool.switch_from.append(pool._node_ids[int(from_node[u])])
+            pool.switch_to.append(
+                pool._node_ids[pool.task_node[int(active[u])]])
 
     def _send_traffic(self, cand, active, probe_ok, frame_ok):
         """Admit one window of fluid traffic and stash its latencies:
@@ -1016,34 +1062,33 @@ class FusedTickDriver:
         jitter draws in the host tick's exact element order (probes
         row-major, then frames user-major)."""
         pool = self.pool
+        span = pool.spans.span
         nf = self.nf
-        p_tasks = cand[probe_ok]
-        p_nodes = pool.task_node[p_tasks]
-        f_nodes = pool.task_node[active[frame_ok]]
-        n_nodes = len(pool._node_ids)
-        counts = np.bincount(p_nodes, minlength=n_nodes)
-        counts += nf * np.bincount(f_nodes, minlength=n_nodes)
-        pool.watch_node_indices(np.nonzero(counts)[0])
-
         p_cnt = int(probe_ok.sum())
         f_cnt = int(frame_ok.sum())
         total = p_cnt + f_cnt * nf
-        if total == 0:
-            return
-        np_cap = self._node_cap()
-        work0 = np.zeros(np_cap, np.float32)
-        net_rate = np.zeros(np_cap, np.float32)
-        now = pool.sim.now
-        for nix in np.nonzero(counts)[0]:
-            cap = pool._node_caps[nix]
-            w0, in_rate, cap_rate = cap.arrive_batch(
-                int(counts[nix]), pool.workload_scale, pool.probe_period,
-                now)
-            work0[nix] = w0
-            net_rate[nix] = in_rate - cap_rate
-        pool.requests_sent += total
-
-        eps = [pool.sim.rng.standard_normal(total) for _ in range(3)]
+        with span("transport.admit"):
+            p_tasks = cand[probe_ok]
+            p_nodes = pool.task_node[p_tasks]
+            f_nodes = pool.task_node[active[frame_ok]]
+            n_nodes = len(pool._node_ids)
+            counts = np.bincount(p_nodes, minlength=n_nodes)
+            counts += nf * np.bincount(f_nodes, minlength=n_nodes)
+            pool.watch_node_indices(np.nonzero(counts)[0])
+            if total == 0:
+                return
+            np_cap = self._node_cap()
+            work0 = np.zeros(np_cap, np.float32)
+            net_rate = np.zeros(np_cap, np.float32)
+            now = pool.sim.now
+            for nix in np.nonzero(counts)[0]:
+                cap = pool._node_caps[nix]
+                w0, in_rate, cap_rate = cap.arrive_batch(
+                    int(counts[nix]), pool.workload_scale,
+                    pool.probe_period, now)
+                work0[nix] = w0
+                net_rate[nix] = in_rate - cap_rate
+            pool.requests_sent += total
 
         def split(e):
             dp = np.zeros(probe_ok.shape, np.float32)
@@ -1052,7 +1097,9 @@ class FusedTickDriver:
             df[frame_ok] = e[p_cnt:].reshape(-1, nf)
             return dp, df
 
-        (e1p, e1f), (e2p, e2f), (e3p, e3f) = map(split, eps)
+        with span("transport.jitter"):
+            eps = [pool.sim.rng.standard_normal(total) for _ in range(3)]
+            (e1p, e1f), (e2p, e2f), (e3p, e3f) = map(split, eps)
         # in-situ data access rides the frame (request) path only — the
         # per-user term is host-computed once and injected into every
         # backend identically (decision identity by construction)
@@ -1066,8 +1113,9 @@ class FusedTickDriver:
                                      minlength=len(reps)) * reads
             pool.am.cargo_manager.note_read_load(
                 pool.service_id, reps, rep_counts, pool.probe_period)
-        self._push_traffic(work0, net_rate, probe_ok, frame_ok, data_f,
-                           ((e1p, e1f), (e2p, e2f), (e3p, e3f)))
+        with span("transport.push"):
+            self._push_traffic(work0, net_rate, probe_ok, frame_ok, data_f,
+                               ((e1p, e1f), (e2p, e2f), (e3p, e3f)))
         self._stash_dirty = True
         if pool._lat_hist is not None:
             # frame-latency histogram (latency_hist=True): each window's
@@ -1083,6 +1131,8 @@ class FusedTickDriver:
                       splits):
         pool = self.pool
         (e1p, e1f), (e2p, e2f), (e3p, e3f) = splits
+        pool.spans.h2d(work0, net_rate, probe_ok, frame_ok, e1p, e2p, e3p,
+                       e1f, e2f, e3f, data_f)
         self.state = _fused_traffic(
             self.state, self.static, work0, net_rate, probe_ok, frame_ok,
             e1p, e2p, e3p, e1f, e2f, e3f, data_f, pool.workload_scale,
@@ -1092,8 +1142,9 @@ class FusedTickDriver:
 
     def _pull(self, arr) -> np.ndarray:
         """Device per-user array -> host numpy in pool (original) user
-        order; the mesh driver overrides with the inverse permutation."""
-        return np.asarray(arr)
+        order (its bytes counted); the mesh driver overrides with the
+        inverse permutation."""
+        return self.pool.spans.d2h(arr)
 
     def _run_flush(self, deaths, n_deaths):
         self.state = _fused_flush(self.state, self.static, deaths,
@@ -1110,7 +1161,7 @@ class FusedTickDriver:
         pool = self.pool
         pool.cand_task = self._pull(self.state.cand)
         pool.active = self._pull(self.state.active)
-        pool.failovers = int(np.asarray(self.state.failovers).sum())
+        pool.failovers = int(pool.spans.d2h(self.state.failovers).sum())
 
     def sync_aggregates(self):
         self.flush()
@@ -1133,7 +1184,7 @@ class FusedTickDriver:
         self.deaths.append(int(node_ix))
 
     def check_overflow(self):
-        if bool(np.asarray(self.state.ema_overflow).any()):
+        if bool(self.pool.spans.d2h(self.state.ema_overflow).any()):
             raise RuntimeError(
                 f"fused tick: a user outgrew its {self.ema_slots} EMA "
                 "slots — restart the pool with a larger ema_slots")
@@ -1322,7 +1373,7 @@ class MeshTickDriver(FusedTickDriver):
         return out
 
     def _pull(self, arr) -> np.ndarray:
-        return np.asarray(arr)[self._pos]
+        return self.pool.spans.d2h(arr)[self._pos]
 
     def _row(self, u: int) -> int:
         return int(self._pos[u])
@@ -1347,14 +1398,16 @@ class MeshTickDriver(FusedTickDriver):
             self._lt_sh = pool_shardings(
                 self.mesh, POOL_LOCAL_TASK_AXES,
                 self.rules)["local_task"]
+        self._count_static(view, st)
+        d2h = pool.spans.d2h
         host = dict(
             user_lat=self._to_dev(ulat), user_lon=self._to_dev(ulon),
             user_net=self._to_dev(unet), user_code20=self._to_dev(ucode),
-            task_lat=np.asarray(st.lat), task_lon=np.asarray(st.lon),
-            task_aff=np.asarray(st.aff),
-            task_code20=np.asarray(st.code20),
-            task_cloud=np.asarray(st.cloud), task_node=tn,
+            task_lat=d2h(st.lat), task_lon=d2h(st.lon),
+            task_aff=d2h(st.aff), task_code20=d2h(st.code20),
+            task_cloud=d2h(st.cloud), task_node=tn,
             node_proc=proc, node_slots=slots)
+        pool.spans.h2d(lt, *host.values())
         self.static = FusedTickStatic(
             shards=None,
             **{k: jax.device_put(v, self._static_sh[k])
@@ -1377,6 +1430,7 @@ class MeshTickDriver(FusedTickDriver):
         ov[0] = overflow
         dev["failovers"] = fo
         dev["ema_overflow"] = ov
+        self.pool.spans.h2d(*dev.values())
         self.state = FusedTickState(
             **{k: jax.device_put(v, self._state_sh[k])
                for k, v in dev.items()})
@@ -1386,11 +1440,11 @@ class MeshTickDriver(FusedTickDriver):
         boundaries — pull the state to pool order under the old
         placement, re-upload under the new one."""
         s = self.state
-        host = {f: np.asarray(getattr(s, f))[old_pos]
-                for f in _STATE_PAD_FILL}
+        d2h = self.pool.spans.d2h
+        host = {f: d2h(getattr(s, f))[old_pos] for f in _STATE_PAD_FILL}
         self._upload_state(
-            host, failovers=int(np.asarray(s.failovers).sum()),
-            overflow=bool(np.asarray(s.ema_overflow).any()))
+            host, failovers=int(d2h(s.failovers).sum()),
+            overflow=bool(d2h(s.ema_overflow).any()))
 
     def init_state(self):
         pool = self.pool
@@ -1432,13 +1486,19 @@ class MeshTickDriver(FusedTickDriver):
         prog = self._programs_for()
         dirty = self._dirty_input()
         r_ok = self._refresh_mask()
-        self.state, outs = prog.tick(
-            self.state, self.static, self._local_task, free, sched,
-            alive, need, deaths, n_deaths, pool.alpha,
-            pool.switch_margin, self._to_dev(r_ok, False),
-            self._to_dev(dirty, False))
+        with pool.spans.span("fused_tick.dispatch"):
+            r_dev = self._to_dev(r_ok, False)
+            d_dev = self._to_dev(dirty, False)
+            pool.spans.h2d(free, sched, alive, need, deaths, n_deaths,
+                           r_dev, d_dev)
+            self.state, outs = prog.tick(
+                self.state, self.static, self._local_task, free, sched,
+                alive, need, deaths, n_deaths, pool.alpha,
+                pool.switch_margin, r_dev, d_dev)
         self._stash_dirty = False
-        if bool(np.asarray(outs.border_overflow).any()):
+        with pool.spans.span("fused_tick.wait"):
+            border_overflow = pool.spans.d2h(outs.border_overflow)
+        if bool(border_overflow.any()):
             raise RuntimeError(
                 f"fused tick: a device's border band exceeded "
                 f"{self.border_cap} users — restart the pool with a "
@@ -1452,11 +1512,12 @@ class MeshTickDriver(FusedTickDriver):
         prog = self._programs_for()
         td = self._to_dev
         (e1p, e1f), (e2p, e2f), (e3p, e3f) = splits
+        host = (td(probe_ok, False), td(frame_ok, False), td(e1p), td(e2p),
+                td(e3p), td(e1f), td(e2f), td(e3f), td(data_f))
+        pool.spans.h2d(work0, net_rate, *host)
         self.state = prog.traffic(
-            self.state, self.static, work0, net_rate,
-            td(probe_ok, False), td(frame_ok, False),
-            td(e1p), td(e2p), td(e3p), td(e1f), td(e2f), td(e3f),
-            td(data_f), pool.workload_scale, pool.frame_interval)
+            self.state, self.static, work0, net_rate, *host,
+            pool.workload_scale, pool.frame_interval)
 
     def _run_flush(self, deaths, n_deaths):
         prog = self._programs_for()
@@ -1470,10 +1531,10 @@ class MeshTickDriver(FusedTickDriver):
         up = self._perm.shape[0]
         self.state = self.state._replace(
             frame_count=jax.device_put(
-                np.zeros(up, np.asarray(self.state.frame_count).dtype),
+                np.zeros(up, self.state.frame_count.dtype),
                 self._state_sh["frame_count"]),
             frame_sum=jax.device_put(
-                np.zeros(up, np.asarray(self.state.frame_sum).dtype),
+                np.zeros(up, self.state.frame_sum.dtype),
                 self._state_sh["frame_sum"]))
 
     def set_running(self, running: np.ndarray):

@@ -14,6 +14,13 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+def _handler_name(fn: Callable) -> str:
+    """``Captain.fail`` for a bound method, a function's qualified name,
+    else the callable's type."""
+    return getattr(fn, "__qualname__", None) or type(fn).__name__
 
 
 @dataclass(order=True)
@@ -67,7 +74,10 @@ class Simulator:
             if ev.cancelled:
                 continue
             self.now = ev.time
-            ev.fn(*ev.args)
+            # a profiler span per event, named after its handler, so a
+            # trace names every host moment of a run
+            with TraceAnnotation(_handler_name(ev.fn)):
+                ev.fn(*ev.args)
             n += 1
         if self._heap and n >= max_events and (
                 until is None or self._heap[0].time <= until):

@@ -202,6 +202,10 @@ def main() -> None:
         "failovers": single.failovers,
         "border_users": int(border.size),
         "cargo_reads": int(reads),
+        # host spans and counters of both drivers (repro.core.spans)
+        "spans": {"single": sorted(single.phase_ms),
+                  "mesh": sorted(mesh.phase_ms)},
+        "counts": {"single": single.counts, "mesh": mesh.counts},
     }
     if refresh_ms:
         # the host-side dirty tracker is shared logic: the mesh driver

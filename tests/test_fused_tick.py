@@ -227,6 +227,66 @@ def test_device_tick_phase_breakdown_recorded():
     assert {"selection", "policy", "transport"} <= set(host.phase_ms)
 
 
+# the device tick's host spans: parent -> children (dotted names)
+SPAN_TREE = {
+    "transport": ("transport.static", "transport.dynamic",
+                  "transport.mirrors", "transport.admit",
+                  "transport.jitter", "transport.push"),
+    "fused_tick": ("fused_tick.dispatch", "fused_tick.wait",
+                   "fused_tick.pull"),
+}
+
+
+def test_device_tick_spans_and_counters_under_churn(monkeypatch):
+    """Every host span of the device tick is recorded and each parent's
+    time covers its children's; the counters count what the ticks moved:
+    one static rebuild per node epoch, every queued break, bytes both
+    ways.  Same shapes as the compile-once test (programs reused)."""
+    from repro.core import fused_tick
+    queued = []
+    on_break = fused_tick.FusedTickDriver.on_break
+
+    def counting_on_break(self, node_ix):
+        queued.append(node_ix)
+        on_break(self, node_ix)
+
+    monkeypatch.setattr(fused_tick.FusedTickDriver, "on_break",
+                        counting_on_break)
+    sys_ = _fluid_system(16, seed=2)
+    rng = np.random.default_rng(3)
+    locs = np.stack([44.97 + rng.uniform(-.5, .5, 50),
+                     -93.22 + rng.uniform(-.5, .5, 50)], axis=1)
+    pool = sys_.make_client_pool(
+        SERVICE, locs=locs, transport="fluid", frame_interval_ms=500.0,
+        selection_backend="geo_topk", tick="device")
+    sys_.sim.at(0.0, pool.start)
+    sys_.fail_node("N2", 2_200.0)
+    sys_.fail_node("N6", 4_300.0)
+    sys_.sim.run(until=6_000.0)
+    sys_.captains["N2"].recover()
+    epochs = 1                      # the pool's start
+    # two replica joins, two windows apart: two more node epochs
+    for node, until in (("N4", 8_100.0), ("N9", 12_100.0)):
+        cap = sys_.captains[node]
+        t = Task(f"{SERVICE}/t_join_{node}", SERVICE, captain=cap,
+                 status="running", ready_at=sys_.sim.now)
+        cap.tasks[t.task_id] = t
+        sys_.am.tasks[SERVICE].append(t)
+        sys_.am.engine.invalidate(SERVICE)
+        epochs += 1
+        sys_.sim.run(until=until)
+    assert pool.ticks_run >= 6
+
+    ms = pool.phase_ms
+    for parent, children in SPAN_TREE.items():
+        assert set(children) <= set(ms), sorted(ms)
+        assert ms[parent] >= sum(ms[c] for c in children) > 0
+    counts = pool.counts
+    assert counts["static_rebuilds"] == epochs
+    assert len(queued) > 0 and counts["breaks"] == len(queued)
+    assert counts["h2d_bytes"] > 0 and counts["d2h_bytes"] > 0
+
+
 def test_device_tick_guard_rails():
     sys_ = _fluid_system(8, seed=1)
     locs = np.zeros((4, 2)) + (44.97, -93.22)

@@ -52,16 +52,37 @@ def _run_child(n_users: int, n_per_region: int, timeout: float = 600.0,
     return json.loads(lines[-1][len(_OUT):])
 
 
-def test_mesh_identity_churn_beacon_failover():
+@pytest.fixture(scope="module")
+def churn_out():
+    return _run_child(2_000, 16)
+
+
+def test_mesh_identity_churn_beacon_failover(churn_out):
     """4-device mesh == single device, decision for decision, through a
     full churn + Beacon-failover cycle (includes the compile-count pin
     and the border-band straddlers — see tests/_mesh_child.py)."""
-    out = _run_child(2_000, 16)
+    out = churn_out
     assert out["ok"]
     assert out["ticks"] >= 8
     assert out["switches"] > 0, "scenario never exercised two-round switch"
     assert out["failovers"] > 0, "scenario never exercised failover"
     assert out["border_users"] > 0
+
+
+def test_mesh_driver_spans_and_counters(churn_out):
+    """The mesh driver records the single-device driver's host spans,
+    rebuilds its static arrays and replays breaks as often, and counts
+    bytes both ways (its padded blocks move other byte totals)."""
+    spans, counts = churn_out["spans"], churn_out["counts"]
+    assert spans["mesh"] == spans["single"]
+    assert {"transport.static", "transport.admit", "transport.push",
+            "fused_tick.dispatch", "fused_tick.wait",
+            "fused_tick.pull"} <= set(spans["mesh"])
+    single, mesh = counts["single"], counts["mesh"]
+    for key in ("static_rebuilds", "breaks"):
+        assert mesh[key] == single[key] > 0, key
+    for key in ("h2d_bytes", "d2h_bytes"):
+        assert mesh[key] > 0 and single[key] > 0, key
 
 
 @pytest.mark.slow
