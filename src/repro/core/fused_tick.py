@@ -5,7 +5,8 @@ round-tripped device↔host: geo_topk scoring on device, then numpy for
 the EMA fold, switch decision and failover pick.  This module runs the
 whole tick as ONE jitted program over the pool's SoA state:
 
-    connection breaks (sequential, host arrival order)
+    connection breaks (host arrival order; only per-user columns change
+                       across breaks, so the per-break loop gathers nothing)
       → EMA fold of the previous traffic window
       → scoring + candidate top-k (same fp32 math as geo_topk)
       → two-round ``switch_decide``
@@ -195,59 +196,110 @@ def _ema_fold_into(nodes_tab, vals_tab, overflow, node, lat, m, alpha):
     return nodes_tab, vals_tab.at[rows, slot].set(new), overflow
 
 
+def _select(masks, cols, fill):
+    """Per row, the column whose mask is set (at most one is), else
+    ``fill``: element-wise selects over static columns, no gather."""
+    out = fill
+    for m, col in zip(masks, cols):
+        out = jnp.where(m, col, out)
+    return out
+
+
+def _replay_deaths(state, tn, deaths, n_deaths):
+    """The replay proper (``_process_deaths`` runs it only when the
+    queue is non-empty)."""
+    k = state.cand.shape[1]
+    running = state.running
+    nodes_tab, vals_tab = state.ema_nodes, state.ema_vals
+    cand_node = jnp.where(state.cand >= 0, tn[jnp.clip(state.cand, 0)], -1)
+    act_node = jnp.where(state.active >= 0,
+                         tn[jnp.clip(state.active, 0)], -1)
+    cand_ema = _ema_get_matrix(nodes_tab, vals_tab, cand_node)
+    # every node that can hit a user during the replay
+    tracked = [cand_node[:, j] for j in range(k)] + [act_node]
+
+    def step(i, carry):
+        c, n, e, active, an, reinit, failovers, popped = carry
+        d = jax.lax.dynamic_index_in_dim(deaths, i, keepdims=False)
+        on_d = an == d
+        for nj in n:
+            on_d = on_d | (nj == d)
+        hit = running & on_d
+        popped = [p | (hit & (t == d)) for p, t in zip(popped, tracked)]
+        # left-compact the kept columns (``compact_rows`` semantics):
+        # output column j takes the kept column of rank j, which can only
+        # sit at column j or later
+        rank, seen = [], jnp.zeros_like(active)
+        for cj, nj in zip(c, n):
+            kj = (cj >= 0) & (nj != d)
+            seen = seen + kj.astype(seen.dtype)
+            rank.append(jnp.where(kj, seen - 1, -1))
+        c, n, e = [[jnp.where(hit, _select([r == j for r in rank[j:]],
+                                           x[j:], fill), x[j])
+                    for j in range(k)]
+                   for x, fill in ((c, -1), (n, -1), (e, jnp.nan))]
+        act_dead = hit & ((active < 0) | (an == d))
+        slot = failover_pick(jnp.stack(c, axis=1), jnp.stack(e, axis=1),
+                             xp=jnp)
+        at = [slot == j for j in range(k)]
+        fail = act_dead & (slot >= 0)
+        lost = act_dead & (slot < 0)
+        active = jnp.where(fail, _select(at, c, -1), active)
+        an = jnp.where(fail, _select(at, n, -1), an)
+        active = jnp.where(lost, -1, active)
+        an = jnp.where(lost, -1, an)
+        failovers = failovers + jnp.sum(fail.astype(jnp.int32))
+        reinit = reinit | lost
+        return c, n, e, active, an, reinit, failovers, popped
+
+    carry = ([state.cand[:, j] for j in range(k)], tracked[:k],
+             [cand_ema[:, j] for j in range(k)], state.active, act_node,
+             state.reinit, state.failovers,
+             [jnp.zeros_like(running) for _ in tracked])
+    c, _, _, active, _, reinit, failovers, popped = jax.lax.fori_loop(
+        0, n_deaths, step, carry)
+    pop = jnp.zeros(nodes_tab.shape, bool)
+    for p, t in zip(popped, tracked):
+        pop = pop | (p[:, None] & (nodes_tab == t[:, None]))
+    vals_tab = jnp.where(pop, jnp.nan, vals_tab)
+    return (nodes_tab, vals_tab, jnp.stack(c, axis=1), active, reinit,
+            failovers)
+
+
 def _process_deaths(state, tn, deaths, n_deaths):
     """Replay queued connection breaks in arrival order — each step is
     ``ClientPool.on_connection_break``'s fluid/armada branch: pop the
     dead node's EMAs for affected users, left-compact their candidate
     rows, instant-failover users whose active died (best known EMA, else
     first candidate, else mark for re-initialization).
+    ``deaths[:n_deaths]`` are node indices (>= 0).
 
-    Pops are accumulated as a slot mask and applied once after the loop.
-    That is exact: the slot map itself never changes during the loop,
-    compaction removes every dead-node candidate before
-    ``failover_pick`` gathers EMAs (so a popped cell is never read
-    inside the loop), and the fold that could re-seed popped cells runs
-    after the mask is applied."""
-    rows = jnp.arange(state.cand.shape[0])
-    running = state.running
-    nodes_tab, vals_tab = state.ema_nodes, state.ema_vals
+    Only the per-user decision vectors change across breaks, so the rest
+    is hoisted out of the per-break loop, exactly:
 
-    def step(i, carry):
-        cand, active, reinit, failovers, popmask = carry
-        d = deaths[i]
-        cand_node = jnp.where(cand >= 0, tn[jnp.clip(cand, 0)], -1)
-        act_node = jnp.where(active >= 0, tn[jnp.clip(active, 0)], -1)
-        hit = running & ((cand_node == d).any(axis=1) | (act_node == d))
-        popmask = popmask | (hit[:, None] & (nodes_tab == d))
-        keep = (cand >= 0) & (cand_node != d)
-        # left-compact kept entries by rank (compact_rows semantics) —
-        # closed-form per output column, no per-row sort
-        rank = jnp.cumsum(keep, axis=1) - 1
-        cols = []
-        for j in range(cand.shape[1]):
-            hitj = keep & (rank == j)
-            src = jnp.argmax(hitj, axis=1)
-            cols.append(jnp.where(hitj.any(axis=1), cand[rows, src], -1))
-        compacted = jnp.stack(cols, axis=1)
-        cand = jnp.where(hit[:, None], compacted, cand)
-        act_dead = hit & ((active < 0) | (act_node == d))
-        cand_node = jnp.where(cand >= 0, tn[jnp.clip(cand, 0)], -1)
-        slot = failover_pick(
-            cand, _ema_get_matrix(nodes_tab, vals_tab, cand_node), xp=jnp)
-        has = slot >= 0
-        picked = cand[rows, jnp.clip(slot, 0)]
-        active = jnp.where(act_dead & has, picked, active)
-        active = jnp.where(act_dead & ~has, -1, active)
-        failovers = failovers + jnp.sum((act_dead & has).astype(jnp.int32))
-        reinit = reinit | (act_dead & ~has)
-        return cand, active, reinit, failovers, popmask
+    * pops are deferred, so the EMA slot map and values do not change
+      inside the loop: each candidate's EMA is looked up once, before it,
+      and travels with its column through compaction (a dead node's
+      column is dropped before ``failover_pick`` reads the EMAs);
+    * candidates only leave a row, and the active only moves to one of
+      the row's candidates (or to -1), so the nodes that can hit a user
+      are its k candidate nodes and its active node at loop entry; a
+      node leaves that set only at its own first break.
 
-    cand, active, reinit, failovers, popmask = jax.lax.fori_loop(
-        0, n_deaths, step,
-        (state.cand, state.active, state.reinit, state.failovers,
-         jnp.zeros(nodes_tab.shape, bool)))
-    vals_tab = jnp.where(popmask, jnp.nan, vals_tab)
-    return nodes_tab, vals_tab, cand, active, reinit, failovers
+    The loop therefore carries per-user (U,) columns: each candidate, its
+    node and its EMA; the active and its node; a "popped" flag for each
+    of the k + 1 tracked nodes; ``reinit`` and the ``failovers`` total.
+    A step is element-wise compares and selects over the k static
+    columns, with no gather or scatter.  After the loop one (U, S) pass
+    NaNs the slots of the popped nodes; the fold that could re-seed them
+    runs later.  An empty queue skips all of it (``lax.cond``) and
+    returns the state's arrays as they are."""
+    def skip(state, tn, deaths, n_deaths):
+        return (state.ema_nodes, state.ema_vals, state.cand, state.active,
+                state.reinit, state.failovers)
+
+    return jax.lax.cond(n_deaths > 0, _replay_deaths, skip,
+                        state, tn, deaths, n_deaths)
 
 
 def _fold_window(state, nodes_tab, vals_tab, tn, alpha):
@@ -927,6 +979,8 @@ class FusedTickDriver:
         arr = np.full(DEATH_QUEUE_MAX, -1, np.int32)
         arr[:len(deaths)] = deaths
         self.pool.spans.count("breaks", len(deaths))
+        # programs that run the replay loop (queue non-empty)
+        self.pool.spans.count("replay_ticks", int(len(deaths) > 0))
         return arr, np.int32(len(deaths))
 
     def _refresh_mask(self):

@@ -6,7 +6,8 @@ clock, and adds the block's wall milliseconds to ``ms[name]``.  Nested
 spans take dotted names under their parent (``transport.admit`` inside
 ``transport``), so a parent's time includes its children's.
 ``count(name, n)`` adds ``n`` to ``counts[name]``: bytes moved between
-host and device, static rebuilds, breaks replayed.
+host and device, static rebuilds, breaks replayed and the programs
+that replay them.
 
 A ``TraceAnnotation`` with no profiler session open records nothing, so
 a span costs the same traced or not, apart from the trace itself.

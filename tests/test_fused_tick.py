@@ -240,18 +240,30 @@ SPAN_TREE = {
 def test_device_tick_spans_and_counters_under_churn(monkeypatch):
     """Every host span of the device tick is recorded and each parent's
     time covers its children's; the counters count what the ticks moved:
-    one static rebuild per node epoch, every queued break, bytes both
-    ways.  Same shapes as the compile-once test (programs reused)."""
+    one static rebuild per node epoch, every queued break, the programs
+    run with a non-empty break queue, bytes both ways.  Same shapes as
+    the compile-once test (programs reused)."""
     from repro.core import fused_tick
-    queued = []
-    on_break = fused_tick.FusedTickDriver.on_break
+    queued, programs = [], []
+    drv = fused_tick.FusedTickDriver
+    on_break, run_tick, run_flush = drv.on_break, drv._run_tick, \
+        drv._run_flush
 
     def counting_on_break(self, node_ix):
         queued.append(node_ix)
         on_break(self, node_ix)
 
-    monkeypatch.setattr(fused_tick.FusedTickDriver, "on_break",
-                        counting_on_break)
+    def counting_tick(self, free, sched, alive, need, deaths, n_deaths):
+        programs.append(int(n_deaths))
+        return run_tick(self, free, sched, alive, need, deaths, n_deaths)
+
+    def counting_flush(self, deaths, n_deaths):
+        programs.append(int(n_deaths))
+        return run_flush(self, deaths, n_deaths)
+
+    monkeypatch.setattr(drv, "on_break", counting_on_break)
+    monkeypatch.setattr(drv, "_run_tick", counting_tick)
+    monkeypatch.setattr(drv, "_run_flush", counting_flush)
     sys_ = _fluid_system(16, seed=2)
     rng = np.random.default_rng(3)
     locs = np.stack([44.97 + rng.uniform(-.5, .5, 50),
@@ -284,6 +296,10 @@ def test_device_tick_spans_and_counters_under_churn(monkeypatch):
     counts = pool.counts
     assert counts["static_rebuilds"] == epochs
     assert len(queued) > 0 and counts["breaks"] == len(queued)
+    replays = sum(n > 0 for n in programs)
+    assert 0 < replays < len(programs)       # some ticks replay, some not
+    assert counts["replay_ticks"] == replays
+    assert sum(programs) == len(queued)
     assert counts["h2d_bytes"] > 0 and counts["d2h_bytes"] > 0
 
 
@@ -472,3 +488,264 @@ def test_data_term_changes_latency_and_decisions():
     assert (not np.array_equal(on.active, off.active)
             or list(on.switch_t) != list(off.switch_t)
             or (on.cand_task != off.cand_task).any())
+
+
+# ---------------------------------------------------------------------------
+# break replay: the hoisted replay against the per-break loop it replaced
+# ---------------------------------------------------------------------------
+
+def _replay_oracle(state, tn, deaths, n_deaths):
+    """The break replay as it was before its per-break work was hoisted:
+    one full step per break, task→node maps, EMA lookups and compaction
+    by per-row gathers, pops OR-ed into a (U, S) mask every step."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.client_pool import failover_pick
+    from repro.core.fused_tick import _ema_get_matrix
+
+    rows = jnp.arange(state.cand.shape[0])
+    running = state.running
+    nodes_tab, vals_tab = state.ema_nodes, state.ema_vals
+
+    def step(i, carry):
+        cand, active, reinit, failovers, popmask = carry
+        d = deaths[i]
+        cand_node = jnp.where(cand >= 0, tn[jnp.clip(cand, 0)], -1)
+        act_node = jnp.where(active >= 0, tn[jnp.clip(active, 0)], -1)
+        hit = running & ((cand_node == d).any(axis=1) | (act_node == d))
+        popmask = popmask | (hit[:, None] & (nodes_tab == d))
+        keep = (cand >= 0) & (cand_node != d)
+        # left-compact kept entries by rank (compact_rows semantics) —
+        # closed-form per output column, no per-row sort
+        rank = jnp.cumsum(keep, axis=1) - 1
+        cols = []
+        for j in range(cand.shape[1]):
+            hitj = keep & (rank == j)
+            src = jnp.argmax(hitj, axis=1)
+            cols.append(jnp.where(hitj.any(axis=1), cand[rows, src], -1))
+        compacted = jnp.stack(cols, axis=1)
+        cand = jnp.where(hit[:, None], compacted, cand)
+        act_dead = hit & ((active < 0) | (act_node == d))
+        cand_node = jnp.where(cand >= 0, tn[jnp.clip(cand, 0)], -1)
+        slot = failover_pick(
+            cand, _ema_get_matrix(nodes_tab, vals_tab, cand_node), xp=jnp)
+        has = slot >= 0
+        picked = cand[rows, jnp.clip(slot, 0)]
+        active = jnp.where(act_dead & has, picked, active)
+        active = jnp.where(act_dead & ~has, -1, active)
+        failovers = failovers + jnp.sum((act_dead & has).astype(jnp.int32))
+        reinit = reinit | (act_dead & ~has)
+        return cand, active, reinit, failovers, popmask
+
+    cand, active, reinit, failovers, popmask = jax.lax.fori_loop(
+        0, n_deaths, step,
+        (state.cand, state.active, state.reinit, state.failovers,
+         jnp.zeros(nodes_tab.shape, bool)))
+    vals_tab = jnp.where(popmask, jnp.nan, vals_tab)
+    return nodes_tab, vals_tab, cand, active, reinit, failovers
+
+
+REPLAY_OUTS = ("ema_nodes", "ema_vals", "cand", "active", "reinit",
+               "failovers")
+
+
+def _replay_state(case, k, *, seed=0, u=1_500, s=64, n_nodes=160,
+                  n_tasks=240):
+    """A random pool state and break queue at a CPU size: several tasks
+    per node and a few on none, distinct left-compacted candidates
+    (some rows short or empty), actives on and off their lists, EMA
+    tables of unique nodes with popped (NaN) and free slots."""
+    import jax.numpy as jnp
+    from repro.core.fused_tick import DEATH_QUEUE_MAX, FusedTickState
+
+    rng = np.random.default_rng(seed)
+    tn = rng.integers(0, n_nodes, n_tasks).astype(np.int32)
+    tn[rng.random(n_tasks) < 0.05] = -1                 # on no node
+    n_cand = np.where(rng.random(u) < 0.8, k, rng.integers(0, k + 1, u))
+    cand = np.full((u, k), -1, np.int32)
+    for r in range(u):
+        cand[r, :n_cand[r]] = rng.choice(n_tasks, n_cand[r], replace=False)
+    first = cand[:, 0].copy()
+    active = first.copy()
+    off = rng.random(u) < 0.1
+    active[off] = rng.integers(0, n_tasks, off.sum())
+    active[rng.random(u) < 0.05] = -1
+    running = rng.random(u) < 0.95
+    if case == "active_off_list":
+        active[:] = rng.integers(0, n_tasks, u)
+    elif case == "no_active":
+        active[:] = -1
+    elif case == "not_running":
+        running = rng.random(u) < 0.5
+
+    nodes_tab = np.full((u, s), -1, np.int32)
+    vals_tab = np.full((u, s), np.nan, np.float32)
+    for r in range(u):
+        known = [int(tn[t]) for t in list(cand[r]) + [active[r]]
+                 if t >= 0 and tn[t] >= 0 and rng.random() < 0.8]
+        extra = rng.choice(n_nodes, rng.integers(0, s // 2), replace=False)
+        mine = list(dict.fromkeys(known + extra.tolist()))[:s]
+        rng.shuffle(mine)
+        m = len(mine)
+        nodes_tab[r, :m] = mine
+        # a few equal EMAs, so ties in the failover pick are exercised
+        vals_tab[r, :m] = rng.integers(5, 60, m).astype(np.float32)
+        vals_tab[r, :m][rng.random(m) < 0.15] = np.nan          # popped
+
+    def nodes_of(tasks):
+        tasks = tasks[tasks >= 0]
+        return [int(x) for x in tn[tasks] if x >= 0]
+
+    if case == "none":
+        q = []
+    elif case == "single":
+        q = nodes_of(first)[:1]
+    elif case == "burst":
+        q = rng.integers(0, n_nodes, DEATH_QUEUE_MAX).tolist()
+    elif case == "duplicates":
+        a, b, c = rng.choice(n_nodes, 3, replace=False).tolist()
+        q = [a, b, a, a, c, b, c, a]
+    elif case == "chains":
+        # actives' nodes die, then the nodes failover can land on, in
+        # candidate order: replacements die later in the same queue
+        q = []
+        for j in range(k):
+            q += list(dict.fromkeys(nodes_of(cand[:8, j])))
+        q = q[:DEATH_QUEUE_MAX]
+    elif case == "lose_all":
+        q = list(dict.fromkeys(nodes_of(cand[:6].ravel())))
+        q = q[:DEATH_QUEUE_MAX]
+    else:                                        # state-shape cases
+        q = rng.integers(0, n_nodes, 24).tolist()
+    deaths = np.full(DEATH_QUEUE_MAX, -1, np.int32)
+    deaths[:len(q)] = q
+    nf = 2
+    state = FusedTickState(
+        ema_nodes=jnp.asarray(nodes_tab), ema_vals=jnp.asarray(vals_tab),
+        ema_overflow=jnp.zeros((), bool), cand=jnp.asarray(cand),
+        active=jnp.asarray(active),
+        pending=jnp.full((u,), -1, jnp.int32),
+        running=jnp.asarray(running), ticking=jnp.ones((u,), bool),
+        reinit=jnp.asarray(rng.random(u) < 0.03),
+        lat_probe=jnp.full((u, k), jnp.nan, jnp.float32),
+        lat_frame=jnp.full((u, nf), jnp.nan, jnp.float32),
+        cand_traffic=jnp.asarray(cand), active_traffic=jnp.asarray(active),
+        frame_count=jnp.zeros((u,), jnp.int32),
+        frame_sum=jnp.zeros((u,), jnp.float32),
+        failovers=jnp.asarray(7, jnp.int32))
+    return (state, jnp.asarray(tn), jnp.asarray(deaths),
+            jnp.asarray(len(q), jnp.int32))
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("k", [3, 16])
+@pytest.mark.parametrize("case", [
+    "none", "single", "burst", "duplicates", "active_off_list",
+    "no_active", "not_running", "chains", "lose_all"])
+def test_break_replay_matches_per_break_loop(case, k):
+    """The hoisted replay gives all six outputs of the per-break loop bit
+    for bit (NaN against NaN, compared as uint32): the EMA table with its
+    pops, candidates, actives, re-init marks and the failover total."""
+    import jax
+    from repro.core.fused_tick import DEATH_QUEUE_MAX, _process_deaths
+    args = _replay_state(case, k, seed=sum(map(ord, case)) + k)
+    want = jax.jit(_replay_oracle)(*args)
+    got = jax.jit(_process_deaths)(*args)
+    for name, w, g in zip(REPLAY_OUTS, want, got):
+        w, g = np.asarray(w), np.asarray(g)
+        assert w.dtype == g.dtype and w.shape == g.shape, name
+        np.testing.assert_array_equal(_bits(g), _bits(w), err_msg=name)
+    # the case exercises what it names
+    state, n = args[0], int(args[3])
+    fails = int(want[5]) - int(state.failovers)
+    active0, active1 = np.asarray(state.active), np.asarray(want[3])
+    if case == "none":
+        assert n == 0 and fails == 0
+    elif case == "burst":
+        assert n == DEATH_QUEUE_MAX
+    elif case == "chains":
+        # some user failed over more than once within the queue
+        assert fails > int((active1 != active0).sum())
+    elif case == "lose_all":
+        assert (np.asarray(want[4]) & ~np.asarray(state.reinit)).any()
+    elif case == "no_active":
+        assert (active1 >= 0).any()
+    if case != "none":
+        assert fails > 0
+        assert np.isnan(np.asarray(want[1])).sum() \
+            > np.isnan(np.asarray(state.ema_vals)).sum()
+
+
+def _subjaxprs(eqn):
+    """The jaxprs inside one equation's parameters (loop bodies, cond
+    branches, nested calls)."""
+    import jax
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(x, jax.extend.core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jax.extend.core.Jaxpr):
+                yield x
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _subjaxprs(eqn):
+            yield from _eqns(sub)
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in ``jaxpr`` and its sub-jaxprs."""
+    return {eqn.primitive.name for eqn in _eqns(jaxpr)}
+
+
+def _replay_branches(k):
+    """The replay's jaxpr is one ``cond`` on the queue length, with no
+    loop outside it; returns its branches (empty queue, non-empty)."""
+    import jax
+    from repro.core.fused_tick import _process_deaths
+    args = _replay_state("burst", k, u=64, s=8, n_nodes=20, n_tasks=40)
+    jaxpr = jax.make_jaxpr(_process_deaths)(*args).jaxpr
+    top = [e.primitive.name for e in jaxpr.eqns]
+    assert top.count("cond") == 1 and "while" not in top, top
+    cond, = (e for e in jaxpr.eqns if e.primitive.name == "cond")
+    empty, busy = (b.jaxpr for b in cond.params["branches"])
+    return empty, busy
+
+
+@pytest.mark.parametrize("k", [3, 16])
+def test_break_replay_loop_has_no_gather_or_scatter(k):
+    """A step of the replay touches per-user columns only: the body of
+    its ``while`` loop holds no gather or scatter."""
+    _, busy = _replay_branches(k)
+    loops = [e for e in _eqns(busy) if e.primitive.name == "while"]
+    assert len(loops) == 1
+    body = _primitives(loops[0].params["body_jaxpr"].jaxpr)
+    bad = {p for p in body if "gather" in p or "scatter" in p}
+    assert not bad, f"per-break loop gathers or scatters: {bad}"
+    assert "dynamic_slice" in body          # one queued death per step
+
+
+def test_break_replay_empty_queue_runs_nothing():
+    """With no breaks queued the replay runs no loop, no lookup and no
+    (U, S) pass — the empty-queue branch of its ``cond`` holds no
+    equation — and hands back the state's arrays bit for bit, even with
+    stale node indices left in the queue past ``n_deaths``."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.fused_tick import _process_deaths
+    empty, busy = _replay_branches(3)
+    assert not empty.eqns
+    assert "while" in _primitives(busy)
+    state, tn, deaths, _ = _replay_state("burst", 3, seed=5)
+    got = jax.jit(_process_deaths)(state, tn, deaths,
+                                   jnp.asarray(0, jnp.int32))
+    for name, g in zip(REPLAY_OUTS, got):
+        np.testing.assert_array_equal(_bits(g), _bits(getattr(state, name)),
+                                      err_msg=name)
